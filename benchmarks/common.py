@@ -1,10 +1,29 @@
 """Shared helpers for the paper benchmarks."""
 from __future__ import annotations
 
+import os
 import time
 from typing import Optional
 
 from repro.sim import build_simulation
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def set_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call once before compiling.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing is changed.  Otherwise the cache goes to the fixed path
+    ``<repo>/.jax_cache`` (a moving path never hits).  Returns the directory.
+    """
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO_ROOT, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
 
 
 def run_sim(algo: str, n: int, *, batch: int = 4, network: str = "sdc",

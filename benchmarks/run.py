@@ -30,7 +30,10 @@ def main() -> None:
                     help="dump results as JSON to PATH")
     args = ap.parse_args()
 
-    from . import (arch_microbench, common, lease_read, net_loopback,
+    from . import common
+
+    common.set_compile_cache()
+    from . import (arch_microbench, lease_read, net_loopback,
                    obs_overhead, paper_fig3_batching, paper_fig4_scaling,
                    paper_fig5_failures, paper_fig6_robustness,
                    paper_table3_connectivity, smr_throughput, sweep_vec,

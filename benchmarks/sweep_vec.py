@@ -11,54 +11,67 @@ speedup; the driver's ``--json`` dump records the trajectory.
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
+
+import numpy as np
 
 from repro.vecsim import grid, sweep
 
 from .common import emit, run_sim
 
+WINDOW = (3, 10)    # summary window of the sweep rows
 
-def _grid(full: bool):
+
+def sweep_grid():
+    """The 768-config grid: 3 algorithms x n x d x network x 16 seeds."""
     return grid(algo=("allconcur+", "allconcur", "allgather"),
                 n=(8, 16, 32, 64), d=(2, 3), network=("sdc", "uniform"),
                 seed=range(16), rounds=12)
 
 
-def _run_event(cfg, window=(3, 10)):
+def _run_event(cfg, window=WINDOW):
     met, _wall = run_sim(cfg.algo, cfg.n, batch=cfg.batch,
                          network=cfg.network, rounds=cfg.rounds,
                          max_time=60.0, d=cfg.resolved_d())
     return met.median_latency(), met.throughput(*window)
 
 
-def _steady_round(times) -> float:
-    """Steady-state round period of a failure-free timeline (server 0)."""
-    import numpy as np
-    e = np.asarray(times.start)
-    return float(e[-1, 0] - e[-2, 0])
+@dataclass(frozen=True)
+class SMRShape:
+    """Shape of the vectorized SMR client rows (one deployment at n=8)."""
+    n: int
+    batch: int
+    util: float
+    mode: str
+    clients: int
+    q: int              # requests per client, open loop
+    du: float           # steady-state DUAL round period
+    dr: float           # steady-state reliable (recovery) round latency
+    rate: float         # per-client request rate
+    rounds: int         # open-loop horizon
+    schedules: int      # crash schedules
+    mc_rounds: int      # crash-schedule horizon (one request per client)
+
+    @property
+    def cps(self) -> int:
+        return self.clients // self.n
 
 
-def _smr_vec_rows(full: bool) -> None:
-    """Vectorized SMR client rows: >=1e5 open-loop clients per deployment
-    (1e6 under --full) replayed against SMR-sized round timelines, plus a
-    Monte-Carlo crash-schedule variant.  Simulated time is deterministic, so
-    the p50-based us_per_call sits in check_bench's strict band."""
-    import time as _time
-
-    import numpy as np
-
-    from repro.vecsim.clients import (arrival_times, client_latencies,
-                                      mc_client_latencies, server_streams,
-                                      smr_round_times)
-    from repro.vecsim.failures import monte_carlo_times
+def smr_shape(full: bool) -> SMRShape:
+    """>=1e5 open-loop clients per deployment (1e6 under ``full``) at
+    ``util`` of the DUAL capacity; computes the round periods it is sized
+    by (two short engine runs)."""
+    from repro.vecsim.clients import smr_round_times
 
     n, batch, util, mode = 8, 64, 0.6, "allconcur+"
     clients = 1_000_000 if full else 100_000
-    q = 2                                    # requests per client
+    q = 2
     cps = clients // n
-
-    t0 = _time.time()
-    du = _steady_round(smr_round_times(mode, n, reqs_per_round=batch,
-                                       rounds=16))
+    e = np.asarray(smr_round_times(mode, n, reqs_per_round=batch,
+                                   rounds=16).start)
+    du = float(e[-1, 0] - e[-2, 0])          # steady round period, server 0
+    r = smr_round_times("allconcur", n, reqs_per_round=batch, rounds=16)
+    dr = float(np.asarray(r.completion)[-1, 0] - np.asarray(r.start)[-1, 0])
     # DUAL payloads ride two rounds (fresh + duplicate), so a server's
     # sustained capacity is batch/(2 du) req/s; run at `util` of it, with
     # enough rounds to drain the whole backlog plus slack
@@ -66,52 +79,65 @@ def _smr_vec_rows(full: bool) -> None:
     rate = util * cap / cps
     # horizon: the per-client arrival span is Gamma(q, 1/rate) with mean
     # `base` rounds — cover 6x the mean so the unserved (censored) tail of
-    # late arrivals is negligible (~1e-4 for q=2)
+    # late arrivals is negligible (~1e-4 for q=2); one request per client
+    # under crash schedules, over 8x its mean span
     base = int(cps * q / (util * batch / 2))
-    rounds = 6 * base + 64
-    times = smr_round_times(mode, n, reqs_per_round=batch, rounds=rounds)
-    s = server_streams(arrival_times(0, clients, q, rate), n)
+    mc_base = int(cps / (util * batch / 2))
+    return SMRShape(n=n, batch=batch, util=util, mode=mode, clients=clients,
+                    q=q, du=du, dr=dr, rate=rate, rounds=6 * base + 64,
+                    schedules=256 if full else 64,
+                    mc_rounds=8 * mc_base + 64)
+
+
+def _smr_vec_rows(full: bool) -> None:
+    """Vectorized SMR client rows: open-loop clients replayed against
+    SMR-sized round timelines, plus a Monte-Carlo crash-schedule variant.
+    Simulated time is deterministic, so the p50-based us_per_call sits in
+    check_bench's strict band."""
+    from repro.vecsim.clients import (arrival_times, client_latencies,
+                                      mc_client_latencies, server_streams,
+                                      smr_round_times)
+    from repro.vecsim.failures import monte_carlo_times
+
+    t0 = time.time()
+    sh = smr_shape(full)
+    times = smr_round_times(sh.mode, sh.n, reqs_per_round=sh.batch,
+                            rounds=sh.rounds)
+    s = server_streams(arrival_times(0, sh.clients, sh.q, sh.rate), sh.n)
     res = client_latencies(np.asarray(times.start).T,
                            np.asarray(times.completion).T, s,
-                           mode=mode, batch_max=batch)
-    wall = _time.time() - t0
+                           mode=sh.mode, batch_max=sh.batch)
+    wall = time.time() - t0
     p = res.percentiles
     emit("smr_vec_latency_n8", p[0.5] * 1e6,
          f"p50_ms={p[0.5]*1e3:.4f};p99_ms={p[0.99]*1e3:.4f};"
-         f"p999_ms={p[0.999]*1e3:.4f};clients_simulated={clients};"
-         f"served={res.served};rounds={rounds};wall_s={wall:.1f}")
+         f"p999_ms={p[0.999]*1e3:.4f};clients_simulated={sh.clients};"
+         f"served={res.served};rounds={sh.rounds};wall_s={wall:.1f}")
 
     # ---- Monte-Carlo crash schedules: same population, one request each,
-    # replayed against spliced (crash + recovery) timelines
-    t0 = _time.time()
-    dr_times = smr_round_times("allconcur", n, reqs_per_round=batch,
-                               rounds=16)
-    dr = float(np.asarray(dr_times.completion)[-1, 0]
-               - np.asarray(dr_times.start)[-1, 0])
-    schedules = 256 if full else 64
-    mc_q = 1
-    mc_rate = util * cap / cps
-    mc_rounds = 8 * int(cps * mc_q / (util * batch / 2)) + 64
-    # ~2 crashes per schedule horizon: the pooled tail (p999) is shaped by
-    # detection + recovery splices while p50 stays near failure-free
-    mct = monte_carlo_times(du, dr, n=n, batch=batch,
-                            mtbf=mc_rounds * du / 2,
-                            rounds=mc_rounds, n_schedules=schedules, seed=7)
-    s_mc = server_streams(arrival_times(1, clients, mc_q, mc_rate), n)
-    mc = mc_client_latencies(mct.entry, mct.deliver, s_mc, mode=mode,
-                             batch_max=batch)
-    wall = _time.time() - t0
+    # replayed against spliced (crash + recovery) timelines.  ~2 crashes per
+    # schedule horizon: the pooled tail (p999) is shaped by detection +
+    # recovery splices while p50 stays near failure-free
+    t0 = time.time()
+    mct = monte_carlo_times(sh.du, sh.dr, n=sh.n, batch=sh.batch,
+                            mtbf=sh.mc_rounds * sh.du / 2,
+                            rounds=sh.mc_rounds, n_schedules=sh.schedules,
+                            seed=7)
+    s_mc = server_streams(arrival_times(1, sh.clients, 1, sh.rate), sh.n)
+    mc = mc_client_latencies(mct.entry, mct.deliver, s_mc, mode=sh.mode,
+                             batch_max=sh.batch)
+    wall = time.time() - t0
     mp = mc["percentiles"]
     emit("smr_vec_mc_crash_n8", mp[0.5] * 1e6,
          f"p50_ms={mp[0.5]*1e3:.4f};p99_ms={mp[0.99]*1e3:.4f};"
-         f"p999_ms={mp[0.999]*1e3:.4f};clients_simulated={clients};"
-         f"served={mc['served']};schedules={schedules};"
-         f"rounds={mc_rounds};wall_s={wall:.1f}")
+         f"p999_ms={mp[0.999]*1e3:.4f};clients_simulated={sh.clients};"
+         f"served={mc['served']};schedules={sh.schedules};"
+         f"rounds={sh.mc_rounds};wall_s={wall:.1f}")
 
 
 def main(full: bool = False) -> None:
-    cfgs = _grid(full)
-    window = (3, 10)
+    cfgs = sweep_grid()
+    window = WINDOW
 
     t0 = time.time()
     res = sweep(cfgs, window=window)

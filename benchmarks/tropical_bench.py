@@ -3,8 +3,10 @@
 Pushes the same 768-config grid as ``sweep_vec`` (seeds x n x d x algorithm
 x network) through ``repro.vecsim.sweep`` with both inner-relaxation
 engines and cross-checks the results bit-for-bit.  Off-TPU the Pallas path
-runs in interpret mode, so the emitted ratio is the *emulation overhead*;
-on a TPU backend the kernel compiles and the same rows record the speedup.
+runs in interpret mode, so the emitted ratio is the *emulation overhead*,
+and every row names the platform it ran on.  On a TPU the engine's Pallas
+path is refused (the engine runs in float64, which Pallas on a TPU lacks);
+``chip_smoke.py`` compiles and checks the kernels there in float32.
 A raw-kernel microbench row compares one blocked ``tropical_matmul``
 against the dense jnp broadcast min-plus it replaces.
 """
@@ -46,9 +48,12 @@ def main(full: bool = False) -> None:
                             results["pallas"].median_latency)
              and np.array_equal(results["vec"].throughput,
                                 results["pallas"].throughput))
-    mode = "compiled" if jax.default_backend() == "tpu" else "interpret"
+    # name the device the rows ran on; off the TPU the Pallas path runs in
+    # interpret mode and its times are not chip times
+    dev = jax.devices()[0]
+    where = f"platform={dev.platform};device_kind={dev.device_kind}"
     emit("tropical_sweep_768", timings["pallas"][1] / len(cfgs) * 1e6,
-         f"configs={len(cfgs)};pallas_mode={mode};bitexact={exact};"
+         f"configs={len(cfgs)};{where};bitexact={exact};"
          f"pallas_warm_s={timings['pallas'][1]:.3f};"
          f"pallas_cold_s={timings['pallas'][0]:.3f};"
          f"vec_warm_s={timings['vec'][1]:.3f};"
@@ -76,7 +81,7 @@ def main(full: bool = False) -> None:
                                                block_n=128, block_k=128))
     same = bool((jnp_mm(a, b) == tropical_matmul(a, b)).all())
     emit("tropical_matmul_raw", t_pal * 1e6,
-         f"m={m};pallas_mode={mode};bitexact={same};"
+         f"m={m};{where};bitexact={same};"
          f"jnp_us={t_jnp*1e6:.1f};pallas_over_jnp_x={t_pal/t_jnp:.2f}")
 
 

@@ -1,44 +1,28 @@
-"""jax version shim for the Pallas/shard_map layer.
+"""The one place that names JAX APIs whose spelling has moved between releases.
 
-The repo tracks two API renames that landed between jax 0.4.x and 0.5+:
+Targets the installed JAX (0.9.x).  Kernels, collectives and vecsim import
+these names from here instead of reaching into ``jax``/``pltpu`` directly, so
+a future rename is a one-line fix in this file:
 
-- ``pltpu.TPUCompilerParams`` became ``pltpu.CompilerParams``.  Every kernel
-  builds its ``compiler_params`` through :data:`CompilerParams` here instead
-  of touching ``pltpu`` directly, so both spellings work.
-- ``jax.experimental.shard_map.shard_map`` was promoted to
-  ``jax.shard_map``.  Collectives import :func:`shard_map` from here.
-
-Policy: kernels and collectives never feature-detect jax themselves — all
-version probing lives in this module so a future rename is a one-line fix.
+- :data:`CompilerParams` — ``pltpu.CompilerParams`` for every Pallas TPU call.
+- :func:`shard_map` — ``jax.shard_map``.
+- :func:`axis_size` — ``jax.lax.axis_size`` inside ``shard_map``.
+- :func:`enable_x64` — the float64 context (``jax.enable_x64``) that the
+  vecsim exactness contracts with the event simulator run under.
 """
 from __future__ import annotations
 
 import jax
 from jax.experimental.pallas import tpu as _pltpu
 
-# pltpu.CompilerParams (jax >= 0.5) vs pltpu.TPUCompilerParams (jax 0.4.x).
-# Both accept dimension_semantics=/vmem_limit_bytes=/... keywords.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    getattr(_pltpu, "TPUCompilerParams")
-
-# jax.shard_map (jax >= 0.5) vs jax.experimental.shard_map (jax 0.4.x).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+CompilerParams = _pltpu.CompilerParams
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
-def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    ``jax.lax.axis_size`` (jax >= 0.5) vs ``jax.core.axis_frame`` (jax
-    0.4.x, where it resolves directly to the bound size).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    import jax.core as jax_core
-    frame = jax_core.axis_frame(axis)
-    return frame.size if hasattr(frame, "size") else frame
+def enable_x64():
+    """Context manager that turns on 64-bit types for the enclosed trace."""
+    return jax.enable_x64(True)
 
 
-__all__ = ["CompilerParams", "axis_size", "shard_map"]
+__all__ = ["CompilerParams", "axis_size", "enable_x64", "shard_map"]

@@ -28,7 +28,7 @@ Exactness: min and broadcast-add are exact in floating point, so the kernel
 is *bit-for-bit* equal to a jnp reference over the same candidate set — the
 property the vecsim cross-validation relies on.  Entries may be ``+inf``
 (non-edges, padding) but not ``-inf``/NaN.  On CPU run ``interpret=True``
-(float64 works); compiled TPU should use float32.
+(float64 works); compiled on a TPU the data must be float32.
 """
 from __future__ import annotations
 
@@ -43,40 +43,58 @@ from .compat import CompilerParams
 BIG = 1e12   # default below-threshold replacement (matches vecsim.engine.BIG)
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None, dtype) -> bool:
+    """Interpret mode by default off the TPU only (the CPU tests).
+
+    A compiled kernel never falls back to the interpreter: float64 data with
+    ``interpret=False`` (the default on a TPU) is refused here, because
+    Pallas on a TPU has no float64.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not interpret and jnp.dtype(dtype) == jnp.float64:
+        raise ValueError("Pallas on a TPU has no float64: pass float32 data "
+                         "or run with interpret=True off the chip")
+    return interpret
+
+
+def _minplus_chunk(a_ref, b_ref, k0, width, acc, thr=None, big=None):
+    """Fold ``width`` contraction columns starting at ``k0`` into ``acc``.
+
+    The chunk is read from the refs with ``pl.ds`` and split into static
+    single-column slices, so every candidate is a (bm, 1) lane broadcast plus
+    a (1, bn) sublane broadcast: 2-D ops only, which Mosaic lowers directly.
+    """
+    ab = a_ref[0, :, pl.ds(k0, width)]            # (bm, width)
+    bb = b_ref[0, pl.ds(k0, width), :]            # (width, bn)
+    for kk in range(width):
+        cand = ab[:, kk:kk + 1] + bb[kk:kk + 1, :]    # (bm, bn)
+        if thr is None:
+            acc = jnp.minimum(acc, cand)
+        else:
+            plain, gated = acc
+            acc = (jnp.minimum(plain, cand),
+                   jnp.minimum(gated, jnp.where(cand >= thr, cand, big)))
+    return acc
 
 
 def _tropical_kernel(a_ref, b_ref, o_ref, *, block_k: int, nk: int):
-    a = a_ref[0]                                  # (bm, Kp)
-    b = b_ref[0]                                  # (Kp, bn)
-
     def body(ki, acc):
-        ab = jax.lax.dynamic_slice_in_dim(a, ki * block_k, block_k, axis=1)
-        bb = jax.lax.dynamic_slice_in_dim(b, ki * block_k, block_k, axis=0)
-        cand = ab[:, :, None] + bb[None, :, :]    # (bm, bk, bn)
-        return jnp.minimum(acc, jnp.min(cand, axis=1))
+        return _minplus_chunk(a_ref, b_ref, ki * block_k, block_k, acc)
 
-    acc0 = jnp.full((a.shape[0], b.shape[1]), jnp.inf, a.dtype)
+    acc0 = jnp.full(o_ref.shape[1:], jnp.inf, o_ref.dtype)
     o_ref[0] = jax.lax.fori_loop(0, nk, body, acc0)
 
 
 def _tropical_threshold_kernel(a_ref, b_ref, t_ref, o_ref, othr_ref, *,
                                block_k: int, nk: int, big: float):
-    a = a_ref[0]
-    b = b_ref[0]
     t = t_ref[0]                                  # (bm, bn)
 
     def body(ki, accs):
-        acc, acc_thr = accs
-        ab = jax.lax.dynamic_slice_in_dim(a, ki * block_k, block_k, axis=1)
-        bb = jax.lax.dynamic_slice_in_dim(b, ki * block_k, block_k, axis=0)
-        cand = ab[:, :, None] + bb[None, :, :]    # (bm, bk, bn)
-        gated = jnp.where(cand >= t[:, None, :], cand, big)
-        return (jnp.minimum(acc, jnp.min(cand, axis=1)),
-                jnp.minimum(acc_thr, jnp.min(gated, axis=1)))
+        return _minplus_chunk(a_ref, b_ref, ki * block_k, block_k, accs,
+                              thr=t, big=big)
 
-    acc0 = jnp.full((a.shape[0], b.shape[1]), jnp.inf, a.dtype)
+    acc0 = jnp.full(o_ref.shape[1:], jnp.inf, o_ref.dtype)
     out, out_thr = jax.lax.fori_loop(0, nk, body, (acc0, acc0))
     o_ref[0] = out
     othr_ref[0] = out_thr
@@ -160,8 +178,8 @@ def tropical_matmul(a, b, *, block_m: int = 128, block_n: int = 128,
     matching ``a``'s leading dims.  +inf entries (non-edges / padding) are
     handled exactly; the result is bit-for-bit equal to the jnp reference.
     """
-    interpret = _default_interpret() if interpret is None else interpret
     af, bf, _tf, d = _prep(a, b, None, block_m, block_n, block_k)
+    interpret = resolve_interpret(interpret, d["dtype"])
     return _call(_tropical_kernel, af, bf, None, d, interpret, 1)
 
 
@@ -178,8 +196,8 @@ def tropical_matmul_threshold(a, b, thresh, *, big: float = BIG,
     G_R install rule where an early copy is replaced by a BIG sentinel.
     ``thresh`` broadcasts against the (..., M, N) output.
     """
-    interpret = _default_interpret() if interpret is None else interpret
     af, bf, tf, d = _prep(a, b, thresh, block_m, block_n, block_k)
+    interpret = resolve_interpret(interpret, d["dtype"])
     kernel = functools.partial(_tropical_threshold_kernel, big=big)
     return _call(kernel, af, bf, tf, d, interpret, 2)
 

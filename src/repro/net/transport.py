@@ -154,12 +154,15 @@ class NetNode:
         self._stopped = True
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
         for ch in self._out.values():
             if ch.task is not None:
                 ch.task.cancel()
         for w in list(self._rx_conn.values()):
             w.close()
+        if self._server is not None:
+            # Python >= 3.12 waits here for every accepted connection, so
+            # the inbound streams are closed first
+            await self._server.wait_closed()
         await asyncio.sleep(0)   # let cancellations unwind
 
     def pump(self) -> None:

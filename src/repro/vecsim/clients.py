@@ -43,7 +43,11 @@ The model (cross-validated at **zero tolerance** against
 
 Exactness contract: given a round timeline, ack times equal the event
 simulator's **bit-for-bit** (the ack is a gather of the same float, the
-latency the same two floats subtracted).  End-to-end against
+latency the same two floats subtracted).  The device computes only the
+integer part — each request's abcast round, from exact order keys of the
+times (:func:`order_keys`) — and the host gathers the floats, so the
+contract holds on a TPU too, whose float64 is an emulation with fewer
+mantissa bits.  End-to-end against
 :mod:`repro.vecsim.engine` timelines the agreement is the engine's own
 cross-validation tolerance (~1e-12 relative; float association in the
 NIC scan), with SMR-sized cost tables from
@@ -53,8 +57,11 @@ them is exact, the timeline itself is the approximation.
 
 Percentiles use the repo-wide nearest-rank rule
 (:mod:`repro.smr.percentiles`): ``idx = min(int(p * count), count - 1)``
-over the ascending sort, replicated here as a gather so the jit path is
-bit-for-bit equal to the Python helper.
+over the ascending sort, on the host for both :func:`client_latencies` and
+the schedule-pooled :func:`mc_client_latencies` (the device returns the
+round index of every request of every schedule).  Integer time ticks
+(ROADMAP Speed 3) would let the gather and the percentiles move back onto
+the device.
 """
 from __future__ import annotations
 
@@ -64,6 +71,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..smr.percentiles import nearest_rank_index
 from ..smr.workload import ZipfianGenerator
 from .engine import RoundTimes, run_reliable, run_unreliable
 from .topology import reliable_tables, smr_message_bytes, unreliable_tables
@@ -133,7 +141,7 @@ def arrival_times(seed: int, num_clients: int, requests_per_client: int,
     if rate <= 0:
         raise ValueError(f"open-loop arrival requires rate > 0, got {rate!r}")
     jax, jnp = _jax()
-    from jax.experimental import enable_x64
+    from ..kernels.compat import enable_x64
     with enable_x64():
         base = jax.random.PRNGKey(seed)
 
@@ -166,13 +174,61 @@ def server_streams(arrivals, n: int) -> np.ndarray:
 # the jitted pipeline
 
 def _counts_fn(engine: str):
+    """``counts(s, edges, s_keys, edge_keys) -> [n, K]`` int32 arrivals by
+    each edge: exact order keys for "vec", the Pallas kernel on the floats
+    for "pallas"."""
     if engine == "pallas":
         from ..kernels.clients_segred import segment_counts
-        return segment_counts
+        return lambda s, e, s_keys, e_keys: segment_counts(s, e)
     if engine == "vec":
-        from ..kernels.clients_segred import segment_counts_reference
-        return segment_counts_reference
+        return lambda s, e, s_keys, e_keys: _ascending_counts(s_keys, e_keys)
     raise ValueError(f"engine must be 'vec' or 'pallas', got {engine!r}")
+
+
+def order_keys(x) -> Tuple[np.ndarray, np.ndarray]:
+    """Exact ordering keys of float64 times, computed on the host.
+
+    Returns ``(hi, lo)`` uint32 words whose lexicographic order is the
+    numeric order of ``x`` (NaN-free; ``-0.0`` counts as ``+0.0``).  A TPU
+    holds float64 as an emulated pair of float32 with fewer mantissa bits,
+    so comparing the float values there can merge times that differ in
+    their last bits; comparing these words is exact on every backend.
+    """
+    u = (np.asarray(x, np.float64) + 0.0).view(np.uint64)
+    sign = np.uint64(1 << 63)
+    key = np.where(u & sign, ~u, u | sign)
+    return ((key >> np.uint64(32)).astype(np.uint32),
+            (key & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+
+
+def _ascending_counts(s_keys, e_keys):
+    """``#{j : s[h, j] <= edges[h, k]}`` for the pipeline's ascending FIFO
+    rows, by a binary search per edge over :func:`order_keys` words.
+
+    Equal to :func:`repro.kernels.clients_segred.segment_counts_reference`
+    on sorted rows, without its sort (a sort of a 10^6-client stream takes
+    the TPU's compiler minutes) and exact where the float values are not.
+    """
+    jax, jnp = _jax()
+    (s_hi, s_lo), (e_hi, e_lo) = s_keys, e_keys
+    m = s_hi.shape[-1]
+
+    def row(s_hi, s_lo, q_hi, q_lo):
+        def body(_, state):
+            lo, hi = state
+            mid = (lo + hi) // 2
+            at = jnp.minimum(mid, m - 1)
+            le = (s_hi[at] < q_hi) | ((s_hi[at] == q_hi) & (s_lo[at] <= q_lo))
+            live = lo < hi
+            return (jnp.where(live & le, mid + 1, lo),
+                    jnp.where(live & ~le, mid, hi))
+
+        lo = jnp.zeros(q_hi.shape, jnp.int32)
+        hi = jnp.full(q_hi.shape, m, jnp.int32)
+        lo, _ = jax.lax.fori_loop(0, max(m.bit_length(), 1), body, (lo, hi))
+        return lo
+
+    return jax.vmap(row)(s_hi, s_lo, e_hi, e_lo)
 
 
 def _make_cum_scan(jax, jnp, delta: int, batch_max: int):
@@ -189,84 +245,65 @@ def _make_cum_scan(jax, jnp, delta: int, batch_max: int):
     return cum_scan
 
 
-def _pct_gather(jnp, lat_inf_flat, total, ps):
-    """Pooled nearest-rank over a flat +inf-masked latency vector — the jnp
-    twin of repro.smr.percentiles.nearest_rank (same double product, same
-    truncation, same clamp), so jit and Python report identical floats."""
-    x = jnp.sort(lat_inf_flat)
-    out = []
-    for p in ps:
-        idx = jnp.minimum((p * total).astype(jnp.int32), total - 1)
-        v = x[jnp.maximum(idx, 0)]
-        out.append(jnp.where(total > 0, v, jnp.nan))
-    return jnp.stack(out)
-
-
 @functools.lru_cache(maxsize=None)
-def _compiled_pipeline(delta: int, ack_lag: int, batch_max: int,
-                       engine: str, ps: Tuple[float, ...]):
-    """One jit: segment-reduce -> capacity scan -> round assignment -> ack
-    gather -> pooled nearest-rank percentiles.  Static in everything but the
-    timeline/stream arrays; jax re-specializes per shape as usual."""
+def _compiled_pipeline(delta: int, batch_max: int, engine: str):
+    """One jit: segment-reduce -> capacity scan -> round assignment, mapped
+    over timelines.  Its results are integers (the abcast round of every
+    request), exact on any backend; the float ack gather, the latencies and
+    the percentiles are taken from them on the host (:func:`_host_gather`),
+    so they stay bit-exact where the device's float64 is emulated."""
     jax, jnp = _jax()
     _counts = _counts_fn(engine)
     cum_scan = _make_cum_scan(jax, jnp, delta, batch_max)
 
-    def pipeline(entry, ack_times, s):
-        # entry/ack_times: [n, K] per-server round timelines; s: [n, M]
-        k = entry.shape[1]
-        m = s.shape[1]
-        counts = _counts(s, entry)                      # [n, K] int32
-        cum = cum_scan(counts)
-        ranks = jnp.arange(1, m + 1, dtype=cum.dtype)
-        a0 = jax.vmap(
-            lambda c: jnp.searchsorted(c, ranks, side="left"))(cum)
-        ack_idx = a0 + ack_lag
-        valid = (ack_idx < k) & jnp.isfinite(s)
-        ack = jnp.take_along_axis(ack_times, jnp.clip(ack_idx, 0, k - 1),
-                                  axis=1)
-        lat = ack - s
-        cnt = jnp.sum(valid)
-        pct = _pct_gather(jnp, jnp.where(valid, lat, jnp.inf).ravel(),
-                          cnt, ps)
-        return a0, ack, lat, valid, pct, cnt
-
-    return jax.jit(pipeline)
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_mc_pipeline(delta: int, batch_max: int, engine: str,
-                          ps: Tuple[float, ...]):
-    """The schedule-pooled variant: map the assignment pipeline over [S, R]
-    spliced timelines (shared by all servers), keep only the masked latency
-    pool and pooled percentiles so XLA drops per-request intermediates."""
-    jax, jnp = _jax()
-    _counts = _counts_fn(engine)
-    cum_scan = _make_cum_scan(jax, jnp, delta, batch_max)
-
-    def pipeline(entry, deliver, s):
-        # entry/deliver: [S, R]; s: [n, M]
+    def pipeline(entry, s, e_keys, s_keys):
+        # entry: [T, 1 or n, K] round entries per timeline (one row shared
+        # by every server, or one per server); s: [n, M] submits
         n, m = s.shape
-        r = entry.shape[1]
+        k = entry.shape[-1]
         ranks = jnp.arange(1, m + 1, dtype=jnp.int32)
 
         def one(rows):
-            e_row, d_row = rows
-            e = jnp.broadcast_to(e_row, (n, r))
-            counts = _counts(s, e)
+            e, e_hi, e_lo = (jnp.broadcast_to(x, (n, k)) for x in rows)
+            counts = _counts(s, e, s_keys, (e_hi, e_lo))  # [n, K] int32
             cum = cum_scan(counts)
-            a0 = jax.vmap(
+            return jax.vmap(
                 lambda c: jnp.searchsorted(c, ranks, side="left"))(cum)
-            # the MC splice folds the A-delivery lag into `deliver`
-            valid = (a0 < r) & jnp.isfinite(s)
-            ack = d_row[jnp.clip(a0, 0, r - 1)]
-            return jnp.where(valid, ack - s, jnp.inf), jnp.sum(valid)
 
-        lat, cnts = jax.lax.map(one, (entry, deliver))
-        total = jnp.sum(cnts)
-        return _pct_gather(jnp, lat.ravel(), total, ps), total
+        return jax.lax.map(one, (entry,) + tuple(e_keys))   # [T, n, M]
 
     return jax.jit(pipeline)
+
+
+def _assign_rounds(entry, s, *, delta: int, batch_max: int,
+                   engine: str) -> np.ndarray:
+    """0-based abcast round of every request, ``[T, n, M]`` int32, for
+    ``[T, 1 or n, K]`` round entries against ``[n, M]`` submits."""
+    from ..kernels.compat import enable_x64
+    fn = _compiled_pipeline(delta, int(batch_max), engine)
+    with enable_x64():
+        return np.asarray(fn(entry, s, order_keys(entry), order_keys(s)))
+
+
+def _host_gather(a0, ack_times, s, lag: int):
+    """Acks, latencies and the served mask of round assignments ``a0``
+    against ``ack_times`` (same rank; leading axes broadcast), on the host
+    in IEEE float64."""
+    k = ack_times.shape[-1]
+    ack_idx = a0 + lag
+    valid = (ack_idx < k) & np.isfinite(s)
+    ack = np.take_along_axis(ack_times, np.minimum(ack_idx, k - 1), axis=-1)
+    return ack, ack - s, valid
+
+
+def _pooled_percentiles(served: np.ndarray, ps) -> dict:
+    """Nearest-rank percentiles of the 1-D ``served`` latencies (any order;
+    partitioned in place); NaN when empty."""
+    if not served.size:
+        return {p: float("nan") for p in ps}
+    idx = [nearest_rank_index(served.size, float(p)) for p in ps]
+    served.partition(sorted(set(idx)))
+    return {p: float(served[i]) for p, i in zip(ps, idx)}
 
 
 @dataclass(frozen=True)
@@ -297,22 +334,19 @@ def client_latencies(entry, ack_times, submits, *, mode: str,
     ``submits[h, j]``: ascending per-server FIFO submit times
     (:func:`server_streams`); ``+inf`` marks ragged padding.
     """
-    from jax.experimental import enable_x64
     lag = (1 if mode == "allconcur+" else 0) if ack_lag is None else ack_lag
     if batch_max < 1:
         raise ValueError(f"batch_max must be >= 1, got {batch_max}")
-    fn = _compiled_pipeline(_delta(mode), lag, int(batch_max), engine,
-                            tuple(float(p) for p in ps))
-    with enable_x64():
-        a0, ack, lat, valid, pct, cnt = fn(
-            np.asarray(entry, np.float64), np.asarray(ack_times, np.float64),
-            np.asarray(submits, np.float64))
-    pct = np.asarray(pct)
-    return ClientLatencies(
-        round_idx=np.asarray(a0), ack=np.asarray(ack),
-        latency=np.asarray(lat), valid=np.asarray(valid),
-        percentiles={p: float(pct[i]) for i, p in enumerate(ps)},
-        served=int(cnt))
+    entry = np.asarray(entry, np.float64)
+    s = np.asarray(submits, np.float64)
+    a0 = _assign_rounds(entry[None], s, delta=_delta(mode),
+                        batch_max=batch_max, engine=engine)[0]
+    ack, lat, valid = _host_gather(a0, np.asarray(ack_times, np.float64), s,
+                                   lag)
+    served = lat[valid]
+    return ClientLatencies(round_idx=a0, ack=ack, latency=lat, valid=valid,
+                           percentiles=_pooled_percentiles(served, ps),
+                           served=int(served.size))
 
 
 def mc_client_latencies(mc_entry, mc_deliver, submits, *, mode: str,
@@ -326,17 +360,17 @@ def mc_client_latencies(mc_entry, mc_deliver, submits, *, mode: str,
     streams replayed against every schedule.  Returns pooled nearest-rank
     percentiles plus the served-request count.
     """
-    from jax.experimental import enable_x64
-    fn = _compiled_mc_pipeline(_delta(mode), int(batch_max), engine,
-                               tuple(float(p) for p in ps))
-    with enable_x64():
-        pct, total = fn(np.asarray(mc_entry, np.float64),
-                        np.asarray(mc_deliver, np.float64),
-                        np.asarray(submits, np.float64))
-    pct = np.asarray(pct)
-    return {"percentiles": {p: float(pct[i]) for i, p in enumerate(ps)},
-            "served": int(total),
-            "schedules": int(np.asarray(mc_entry).shape[0])}
+    entry = np.asarray(mc_entry, np.float64)
+    s = np.asarray(submits, np.float64)
+    a0 = _assign_rounds(entry[:, None, :], s, delta=_delta(mode),
+                        batch_max=batch_max, engine=engine)   # [S, n, M]
+    _ack, lat, valid = _host_gather(
+        a0, np.asarray(mc_deliver, np.float64)[:, None, :], s, 0)
+    del a0, _ack
+    served = lat[valid]
+    del lat, valid
+    return {"percentiles": _pooled_percentiles(served, ps),
+            "served": int(served.size), "schedules": int(entry.shape[0])}
 
 
 # --------------------------------------------------------------------------

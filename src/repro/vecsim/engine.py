@@ -54,27 +54,9 @@ _EPS = 1e-9         # fixpoint convergence tolerance (seconds): one ns is 4+
                     # chase float-rounding churn through the round pipeline
 
 
-_CACHE_SET = False
-
-
 def _jax():
     import jax
     import jax.numpy as jnp
-    global _CACHE_SET
-    if not _CACHE_SET:
-        _CACHE_SET = True
-        # persistent compilation cache: the large-n jit programs compile once
-        # per machine instead of once per process (CI runs the bench twice)
-        try:
-            import os
-            cache = os.environ.get(
-                "VECSIM_JAX_CACHE",
-                os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                             ".jax_cache"))
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.abspath(cache))
-        except Exception:
-            pass
     return jax, jnp
 
 
@@ -98,16 +80,57 @@ def _nic_scan(jnp, keys, occ, tx0):
     events.  Returns (start times [m], final free time): start is when each
     event's first send begins serializing — replicating the event heap's
     drain order.
+
+    The TPU's compiler spends minutes on a float64 sort of a G_R event list
+    (m = 2Kn) and on a float64 ``cumsum`` (a reduce-window), so on the TPU
+    the same stable order comes from comparison counts and the prefix
+    scans are associative scans (:func:`_nic_scan_tpu`).  Every other
+    backend keeps the sort and the cumulative primitives.
     """
+    import jax
+    return jax.lax.platform_dependent(
+        keys, occ, tx0,
+        tpu=lambda k, o, t: _nic_scan_tpu(jnp, k, o, t),
+        default=lambda k, o, t: _nic_scan_sorted(jnp, k, o, t))
+
+
+def _nic_free(jnp, E_s, occ_s, tx0, cumsum, cummax):
+    """NIC free times of events already in FIFO order (max-plus closed form:
+    ``free_i = max(E_i, free_{i-1}) + occ_i`` via prefix sum and max)."""
+    csum = cumsum(occ_s)
+    prev = csum - occ_s
+    return csum + jnp.maximum(cummax(E_s - prev), tx0)
+
+
+def _nic_scan_sorted(jnp, keys, occ, tx0):
     import jax.lax as lax
     E = keys[-1]
     order = jnp.lexsort(keys)
     E_s, occ_s = E[order], occ[order]
-    csum = jnp.cumsum(occ_s)
-    prev = csum - occ_s
-    free = csum + jnp.maximum(lax.cummax(E_s - prev, axis=0), tx0)
+    free = _nic_free(jnp, E_s, occ_s, tx0, jnp.cumsum,
+                     lambda x: lax.cummax(x, axis=0))
     start_sorted = free - occ_s
     return jnp.zeros_like(E).at[order].set(start_sorted), free[-1]
+
+
+def _nic_scan_tpu(jnp, keys, occ, tx0):
+    import jax.lax as lax
+    E = keys[-1]
+    m = E.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    # before[i, j]: item j precedes item i in the stable lexsort (keys are
+    # times and round numbers: no NaN, no negative zero)
+    before = idx[None, :] < idx[:, None]
+    for k in keys:                                 # least significant first
+        ki, kj = k[:, None], k[None, :]
+        before = (kj < ki) | ((kj == ki) & before)
+    rank = jnp.sum(before, axis=1, dtype=jnp.int32)
+    order = jnp.zeros(m, jnp.int32).at[rank].set(idx)
+    E_s, occ_s = E[order], occ[order]
+    free = _nic_free(jnp, E_s, occ_s, tx0,
+                     lambda x: lax.associative_scan(jnp.add, x),
+                     lambda x: lax.associative_scan(jnp.maximum, x))
+    return free[rank] - occ, free[-1]
 
 
 # ---------------------------------------------------------------------------
@@ -121,8 +144,7 @@ def _nic_scan(jnp, keys, occ, tx0):
 # iterations over [n, n] arrays instead of a joint K-round relaxation).
 
 def _unreliable_round(jax, jnp, tstart, tx0, parent, send_off, occ, prop,
-                      prop_from_parent, max_iters, relax_cost=None,
-                      interpret=True):
+                      prop_from_parent, max_iters, relax_cost=None):
     n = tstart.shape[0]
     eye = jnp.eye(n, dtype=bool)
     tsv = tstart[None, :]                      # round entry, per server column
@@ -147,8 +169,7 @@ def _unreliable_round(jax, jnp, tstart, tx0, parent, send_off, occ, prop,
             # added after the min (single candidate: equivalent) to keep the
             # event sim's (start + send_off) + prop float association
             from ..kernels.tropical import tropical_matmul
-            cand = tropical_matmul(start[:, None, :], relax_cost,
-                                   interpret=interpret)[:, 0, :] \
+            cand = tropical_matmul(start[:, None, :], relax_cost)[:, 0, :] \
                 + prop_from_parent
         A_new = jnp.where(eye, tsv, cand)
         return A_new, E, free_end
@@ -178,6 +199,7 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
     lowers the relaxation onto the tropical min-plus kernel (bit-for-bit
     equal to the default jnp path; interpret-mode off-TPU)."""
     jax, jnp = _jax()
+    from ..kernels.compat import enable_x64
     if engine not in ENGINES:
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     parent = np.asarray(parent)
@@ -192,10 +214,11 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
         return np.asarray(a, np.float64).reshape(
             (-1,) + a.shape[len(batch_shape):])
 
-    C, tstart, iters = fn(
-        parent.reshape((-1, n, n)).astype(np.int32),
-        flat(np.asarray(send_off)), flat(np.asarray(occ)),
-        flat(np.asarray(prop)))
+    with enable_x64():
+        C, tstart, iters = fn(
+            parent.reshape((-1, n, n)).astype(np.int32),
+            flat(np.asarray(send_off)), flat(np.asarray(occ)),
+            flat(np.asarray(prop)))
     C = np.asarray(C).reshape(batch_shape + (K, n))
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
     return RoundTimes(completion=C, start=tstart, iterations=int(np.max(iters)))
@@ -205,10 +228,9 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
 def _compiled_unreliable(n: int, K: int, max_iters: int,
                          engine: str = "vec"):
     jax, jnp = _jax()
-    from jax.experimental import enable_x64
+    from ..kernels.compat import enable_x64
 
     use_pallas = engine == "pallas"
-    interpret = jax.default_backend() != "tpu"
 
     with enable_x64():
         def single(parent, send_off, occ, prop):
@@ -227,7 +249,7 @@ def _compiled_unreliable(n: int, K: int, max_iters: int,
                 tstart, tx0 = carry
                 C, free_end, it = _unreliable_round(
                     jax, jnp, tstart, tx0, parent, send_off, occ, prop,
-                    prop_from_parent, max_iters, relax_cost, interpret)
+                    prop_from_parent, max_iters, relax_cost)
                 return (C, free_end), (tstart, C, it)
 
             init = (jnp.zeros(n, jnp.float64), jnp.zeros(n, jnp.float64))
@@ -235,12 +257,9 @@ def _compiled_unreliable(n: int, K: int, max_iters: int,
                                                 length=K)
             return C, ts, jnp.max(its)
 
-        fn = jax.jit(jax.vmap(single))
-
-        def call(parent, send_off, occ, prop):
-            with enable_x64():
-                return fn(parent, send_off, occ, prop)
-        return call
+        # the program's name is what compile logs and profiles report
+        single.__name__ = f"vecsim_unreliable_n{n}_k{K}_{engine}"
+        return jax.jit(jax.vmap(single))
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +267,7 @@ def _compiled_unreliable(n: int, K: int, max_iters: int,
 # ---------------------------------------------------------------------------
 
 def _reliable_step(jax, jnp, A1, inst, tstart, pred, pred_cost, pred_mask,
-                   occ, t0, pallas_tables=None, interpret=True):
+                   occ, t0, pallas_tables=None):
     """One Jacobi sweep of the joint K-round G_R relaxation.
 
     ``pred[v, j]`` lists v's G_R predecessors (padded, masked by
@@ -306,8 +325,7 @@ def _reliable_step(jax, jnp, A1, inst, tstart, pred, pred_cost, pred_mask,
         cost2, has_pad = pallas_tables                    # [2n, n], [n]
         S2 = jnp.concatenate([start1, start2], axis=-1)   # [K, s, 2n]
         thr = jnp.broadcast_to(tsv, (K, n, n))
-        plain, gated = tropical_matmul_threshold(S2, cost2, thr, big=BIG,
-                                                 interpret=interpret)
+        plain, gated = tropical_matmul_threshold(S2, cost2, thr, big=BIG)
         pad = has_pad[None, None, :]
         A1_new = jnp.where(pad, jnp.minimum(plain, BIG), plain)
         inst_new = jnp.where(pad, jnp.minimum(gated, BIG), gated)
@@ -329,6 +347,7 @@ def run_reliable(adj, edge_off, occ, prop, *, rounds: int,
     min-plus kernel, bit-for-bit equal to the default jnp gather path.
     """
     jax, jnp = _jax()
+    from ..kernels.compat import enable_x64
     if engine not in ENGINES:
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     adj = np.asarray(adj).astype(bool)
@@ -360,13 +379,15 @@ def run_reliable(adj, edge_off, occ, prop, *, rounds: int,
             pred_mask[b, v, :len(us)] = True
 
     fn = _compiled_reliable(n, K, dmax, max_iters, True, engine)
-    C, tstart, iters, resid = fn(pred, pred_cost, pred_mask, occ_f)
+    with enable_x64():
+        C, tstart, iters, resid = fn(pred, pred_cost, pred_mask, occ_f)
     C, resid = np.asarray(C), np.asarray(resid)
     # insurance: the warm-started solve must agree with the trustworthy cold
     # prefix and be fully resolved; otherwise redo the whole batch cold
     if (resid > 1e-9).any() or not np.isfinite(C).all() or (C > BIG / 2).any():
         fn = _compiled_reliable(n, K, dmax, 8 * max_iters, False, engine)
-        C, tstart, iters, _ = fn(pred, pred_cost, pred_mask, occ_f)
+        with enable_x64():
+            C, tstart, iters, _ = fn(pred, pred_cost, pred_mask, occ_f)
         C = np.asarray(C)
     C = C.reshape(batch_shape + (K, n))
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
@@ -377,10 +398,9 @@ def run_reliable(adj, edge_off, occ, prop, *, rounds: int,
 def _compiled_reliable(n: int, K: int, dmax: int, max_iters: int, warm: bool,
                        engine: str = "vec"):
     jax, jnp = _jax()
-    from jax.experimental import enable_x64
+    from ..kernels.compat import enable_x64
 
     use_pallas = engine == "pallas"
-    interpret = jax.default_backend() != "tpu"
 
     with enable_x64():
         def solve(Kc, pred, pred_cost, pred_mask, occ, ts0, iters_cap,
@@ -390,25 +410,31 @@ def _compiled_reliable(n: int, K: int, dmax: int, max_iters: int, warm: bool,
             inst0 = A0 if inst0 is None else inst0
             t0 = jnp.zeros((), jnp.float64)
 
+            # Jacobi sweeps to the fixpoint, then one last sweep from the
+            # converged state that yields C.  The last sweep runs as the
+            # loop's final trip (``done``), so the step body is traced and
+            # compiled once per solve: it keeps the loop's entry times and
+            # iteration count, and takes the sweep's A1, inst and C.
             def cond(state):
-                A1, inst, ts, it, delta = state
-                return (it < iters_cap) & (delta > _EPS)
+                return ~state[-1]
 
             def body(state):
-                A1, inst, ts, it, _ = state
-                A1n, instn, tsn, _C = _reliable_step(
+                A1, inst, ts, it, delta, _C, _done = state
+                final = (it >= iters_cap) | (delta <= _EPS)
+                A1n, instn, tsn, C = _reliable_step(
                     jax, jnp, A1, inst, ts, pred, pred_cost, pred_mask, occ,
-                    t0, pallas_tables, interpret)
+                    t0, pallas_tables)
                 delta = jnp.maximum(
                     jnp.max(jnp.abs(jnp.clip(A1n, 0, BIG) - jnp.clip(A1, 0, BIG))),
                     jnp.max(jnp.abs(jnp.clip(instn, 0, BIG) - jnp.clip(inst, 0, BIG))))
-                return A1n, instn, tsn, it + 1, delta
+                return (A1n, instn, jnp.where(final, ts, tsn),
+                        it + jnp.where(final, 0, 1).astype(jnp.int32), delta,
+                        C, final)
 
-            A1, inst, ts, it, _ = jax.lax.while_loop(
-                cond, body, (A0, inst0, ts0, jnp.int32(0), jnp.float64(BIG)))
-            A1, inst, _ts, C = _reliable_step(
-                jax, jnp, A1, inst, ts, pred, pred_cost, pred_mask, occ, t0,
-                pallas_tables, interpret)
+            C0 = jnp.zeros((Kc, n), jnp.float64)
+            A1, inst, ts, it, _, C, _ = jax.lax.while_loop(
+                cond, body, (A0, inst0, ts0, jnp.int32(0), jnp.float64(BIG),
+                             C0, jnp.bool_(False)))
             return C, ts, it, A1, inst
 
         def single(pred, pred_cost, pred_mask, occ):
@@ -462,12 +488,10 @@ def _compiled_reliable(n: int, K: int, dmax: int, max_iters: int, warm: bool,
             resid = jnp.max(jnp.abs(C[:K1] - C1))
             return C, ts, it1 + it2, resid
 
-        fn = jax.jit(jax.vmap(single))
-
-        def call(pred, pred_cost, pred_mask, occ):
-            with enable_x64():
-                return fn(pred, pred_cost, pred_mask, occ)
-        return call
+        # the program's name is what compile logs and profiles report
+        single.__name__ = (f"vecsim_reliable_n{n}_d{dmax}_k{K}_"
+                           f"{'warm' if warm else 'cold'}_{engine}")
+        return jax.jit(jax.vmap(single))
 
 
 # ---------------------------------------------------------------------------

@@ -143,7 +143,7 @@ def _mc_run(du: float, dr: float, *, n: int, batch: int, mtbf: float,
             n2: Optional[int]):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from ..kernels.compat import enable_x64
 
     du_f = np.asarray(du_by_f if du_by_f is not None
                       else [du] * (max_failures + 1), dtype=np.float64)
@@ -176,7 +176,12 @@ def _mc_run(du: float, dr: float, *, n: int, batch: int, mtbf: float,
 
         def one_schedule(crashes):
             def step(state, idx):
-                t, ptr, f, lat_sum, msg_sum = state
+                # the clock is a compensated sum (t + tc): over a horizon of
+                # 10^4-10^5 rounds a plain float64 sum of round lengths
+                # drifts by ~1e-12 relative (and ~1e-11 in a TPU's emulated
+                # float64), past the timelines' contract
+                t, tc, ptr, f, lat_sum, msg_sum = state
+                now = t + tc
                 post = idx > eon_idx           # new eon's dual digraphs
                 at_eon = idx == eon_idx        # the transitional round
                 du_k = jnp.where(post, du2_a[f], du_a[f])
@@ -184,33 +189,37 @@ def _mc_run(du: float, dr: float, *, n: int, batch: int, mtbf: float,
                 # the transitional round runs reliably on the *old* G_R
                 # (§III-I: the swap applies after its completion)
                 dur = jnp.where(at_eon, dr_a[f], du_k)
-                t_end = t + dur
                 nxt = jnp.where(ptr < max_failures,
                                 crashes[jnp.minimum(ptr, max_failures - 1)],
                                 BIG)
-                crashed = nxt < t_end
+                crashed = nxt < now + dur
                 # crash: wasted prefix + detection + two reliable rounds;
                 # the round's messages deliver at the end of the first one.
                 # A crash sampled inside the previous recovery window (nxt
-                # < t) is detected once that recovery ends: clamp to the
+                # < now) is detected once that recovery ends: clamp to the
                 # round start so latency/duration stay positive.
-                t_rec1 = jnp.maximum(nxt, t) + fd_timeout + dr_k
-                t_next = jnp.where(crashed, t_rec1 + dr_k, t_end)
+                rec1 = (jnp.maximum(nxt, now) - now) + fd_timeout + dr_k
+                step_len = jnp.where(crashed, rec1 + dr_k, dur)
                 # reliable rounds deliver at completion (1x), unreliable
                 # A-delivery lags one round (2x)
-                lat = jnp.where(crashed, t_rec1 - t,
+                lat = jnp.where(crashed, rec1,
                                 jnp.where(at_eon, dur, 2.0 * du_k))
                 alive = jnp.where(post, n_post, n) - f
                 new_f = jnp.minimum(f + crashed.astype(jnp.int32),
                                     max_failures)
-                return ((t_next, ptr + crashed.astype(jnp.int32), new_f,
-                         lat_sum + lat * alive, msg_sum + alive),
-                        (t, t + lat))
+                t_next = t + step_len
+                tc_next = tc + jnp.where(
+                    jnp.abs(t) >= jnp.abs(step_len),
+                    (t - t_next) + step_len, (step_len - t_next) + t)
+                return ((t_next, tc_next, ptr + crashed.astype(jnp.int32),
+                         new_f, lat_sum + lat * alive, msg_sum + alive),
+                        (now, now + lat))
 
-            init = (jnp.float64(0.0), jnp.int32(0), jnp.int32(0),
-                    jnp.float64(0.0), jnp.int64(0))
-            (t, ptr, f, lat_sum, msg_sum), (entry, deliver) = jax.lax.scan(
-                step, init, jnp.arange(rounds))
+            init = (jnp.float64(0.0), jnp.float64(0.0), jnp.int32(0),
+                    jnp.int32(0), jnp.float64(0.0), jnp.int64(0))
+            (t, tc, ptr, f, lat_sum, msg_sum), (entry, deliver) = \
+                jax.lax.scan(step, init, jnp.arange(rounds))
+            t = t + tc
             thr = msg_sum * batch / t            # txn / s / server
             return thr, lat_sum / msg_sum, ptr, t, entry, deliver
 

@@ -45,8 +45,10 @@ _SCRIPT = textwrap.dedent("""
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 
     def run(fn, extra=()):
-        return shard_map(lambda a: fn(a[0], "x", *extra), mesh=mesh,
-                         in_specs=P("x"), out_specs=P("x"))(x)
+        # jitted: jax.make_mesh gives explicit axes, under which an eager
+        # shard_map cannot index by axis_index
+        return jax.jit(shard_map(lambda a: fn(a[0], "x", *extra), mesh=mesh,
+                                 in_specs=P("x"), out_specs=P("x")))(x)
 
     for name, fn, extra in [("ring", ring_allgather, ()),
                             ("doubling", doubling_allgather, ()),

@@ -49,6 +49,10 @@ def test_quiet_proxy_is_a_transparent_pipe():
                 echoed.append(data)
                 writer.write(data[::-1])
                 await writer.drain()
+                # Python >= 3.12: Server.wait_closed() waits for every
+                # connection, so the handler closes its own
+                writer.close()
+                await writer.wait_closed()
 
             target, public = f"uds:{td}/real.sock", f"uds:{td}/pub.sock"
             from repro.net.transport import open_connection, start_server

@@ -8,7 +8,7 @@ therefore the event simulator — exactly.
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from repro.kernels.compat import enable_x64
 
 from repro.kernels.tropical import (tropical_closure, tropical_matmul,
                                     tropical_matmul_threshold, tropical_relax)
@@ -106,6 +106,23 @@ def test_tropical_relax_and_closure_reach_shortest_paths():
         rel = np.asarray(tropical_relax(t0, c64, iters=n - 1))
     np.testing.assert_allclose(clo, dist, rtol=1e-12)
     np.testing.assert_allclose(rel, dist, rtol=1e-12)
+
+
+def test_compiled_kernels_refuse_float64():
+    """Pallas on a TPU has no float64: asking for the compiled kernel with
+    float64 data fails loudly instead of falling back to the interpreter."""
+    from repro.kernels.clients_segred import segment_counts
+    with enable_x64():
+        a = jnp.zeros((4, 8), jnp.float64)
+        b = jnp.zeros((8, 4), jnp.float64)
+        with pytest.raises(ValueError, match="no float64"):
+            tropical_matmul(a, b, interpret=False)
+        with pytest.raises(ValueError, match="no float64"):
+            tropical_matmul_threshold(a, b, jnp.zeros((4, 4)),
+                                      interpret=False)
+        with pytest.raises(ValueError, match="no float64"):
+            segment_counts(jnp.zeros((2, 16)), jnp.zeros((2, 4)),
+                           interpret=False)
 
 
 def test_tropical_matmul_rejects_bad_shapes():

@@ -285,3 +285,39 @@ def test_monte_carlo_eon_round_bounds_validated():
     with pytest.raises(ValueError):
         monte_carlo(1e-4, 3e-4, n=8, batch=4, mtbf=1.0, rounds=10,
                     n_schedules=2, eon_round=10)
+
+
+def test_tpu_nic_scan_order_and_times_match_sorted_scan():
+    """The TPU lowering of the NIC scan (comparison-count ranks, associative
+    prefix scans) replays the same FIFO as the lexsort path.  With integer
+    occupancies and times every prefix sum is exact in any order, so the two
+    agree bit for bit; ties exercise the stable tie-break."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.compat import enable_x64
+
+    rng = np.random.default_rng(5)
+    with enable_x64():
+        for m in (1, 7, 96, 384):
+            E = jnp.asarray(rng.integers(0, 12, m).astype(np.float64))
+            r = jnp.asarray(rng.integers(0, 3, m).astype(np.float64))
+            occ = jnp.asarray(rng.integers(0, 4, m).astype(np.float64))
+            tx0 = jnp.float64(rng.integers(0, 5))
+            tpu = jax.jit(lambda *a: vec_engine._nic_scan_tpu(jnp, *a))
+            ref = jax.jit(lambda *a: vec_engine._nic_scan_sorted(jnp, *a))
+            st, ft = tpu((r, E), occ, tx0)
+            sr, fr = ref((r, E), occ, tx0)
+            np.testing.assert_array_equal(np.asarray(st), np.asarray(sr))
+            assert float(ft) == float(fr)
+
+
+def test_monte_carlo_long_horizon_clock_keeps_contract():
+    """The splice clock is a compensated sum: over 52144 rounds (the 10^6
+    client crash-schedule horizon) a plain float64 sum of this round length
+    drifts 1.1e-12 from rounds x du, past the 1e-12 timeline contract."""
+    du, rounds = 0.00022202799999999998, 52144
+    mc = monte_carlo(du, 3 * du, n=8, batch=64, mtbf=1e12, rounds=rounds,
+                     n_schedules=4, seed=7)
+    assert mc.crashes.max() == 0
+    np.testing.assert_allclose(mc.total_time, rounds * du, rtol=1e-12)

@@ -26,98 +26,11 @@ from repro.vecsim.clients import (arrival_times, client_latencies,
                                   closed_loop_latencies, keys_from_uniform,
                                   mc_client_latencies, server_streams,
                                   smr_round_times, zipf_cdf)
+from repro.vecsim.eventref import (event_timelines, run_open_loop,
+                                   server_fifo)
 from repro.vecsim.failures import monte_carlo, monte_carlo_times
 
 MODES = ("allconcur+", "allconcur", "allgather")
-
-
-# ------------------------------------------------------------------ helpers
-
-def _instrument(sim, smr, n, dual):
-    """Record per-server round timelines and per-uid submit/ack times at the
-    simulator's own floats.  ``payload_for`` / ``on_deliver_cb`` are plain
-    instance attributes on the servers, so the harness-installed callbacks
-    can be wrapped after ``build_smr_simulation`` returns."""
-    entries = {h: {} for h in range(n)}
-    compl = {h: {} for h in range(n)}
-    for h in range(n):
-        srv = sim.servers[h]
-        orig_pf = srv.payload_for
-
-        def pf(rnd, _h=h, _o=orig_pf):
-            entries[_h][rnd] = sim.now
-            return _o(rnd)
-
-        srv.payload_for = pf
-        orig_cb = srv.on_deliver_cb
-
-        def cb(rec, _h=h, _o=orig_cb):
-            # DUAL A-delivers round r at the completion of round r+1
-            compl[_h][rec.round + 1 if dual else rec.round] = sim.now
-            if _o:
-                _o(rec)
-
-        srv.on_deliver_cb = cb
-    subs, acks = {}, {}
-    o_sub, o_ack = smr.on_submit, smr.on_ack
-
-    def on_submit(uid, t):
-        subs.setdefault(uid, t)
-        o_sub(uid, t)
-
-    def on_ack(uid, t, is_read):
-        if uid not in acks:
-            acks[uid] = t
-        o_ack(uid, t, is_read)
-
-    smr.on_submit, smr.on_ack = on_submit, on_ack
-    return entries, compl, subs, acks
-
-
-def _timelines(entries, compl, n):
-    """Dense [n, K] entry/completion arrays (E[h, k] = entry of round k+1).
-    completion(r) == entry(r+1) is the same simulator event, so the shared
-    rounds reuse the identical float."""
-    k = min(max(entries[h]) for h in range(n))
-    e = np.full((n, k), np.inf)
-    c = np.full((n, k), np.inf)
-    for h in range(n):
-        for r in range(1, k + 1):
-            e[h, r - 1] = entries[h][r]
-        for r, t in compl[h].items():
-            if r <= k:
-                c[h, r - 1] = t
-        c[h, :k - 1] = e[h, 1:]
-    return e, c
-
-
-def _server_fifo(subs, acks, n):
-    """Per-server FIFO uid order + padded [n, M] submit-time streams."""
-    by_server = {h: sorted((u for u in subs if u[0] % n == h),
-                           key=lambda u: (subs[u], u[0]))
-                 for h in range(n)}
-    m = max(len(us) for us in by_server.values())
-    s = np.full((n, m), np.inf)
-    for h, us in by_server.items():
-        s[h, :len(us)] = [subs[u] for u in us]
-    return by_server, s
-
-
-def _run_open_loop(algo, n, *, cps=2, rpc=6, batch_max=2, rate=3000.0):
-    cfg = WorkloadConfig(read_ratio=0.0, distribution="uniform", nkeys=64,
-                         num_clients=cps * n, value_size=16,
-                         linearizable_reads=True, arrival="open",
-                         open_rate=rate, seed=0)
-    sim, smr, _services = build_smr_simulation(
-        algo, n, workload=cfg, requests_per_client=rpc,
-        batch_max=batch_max, network="sdc")
-    rec = _instrument(sim, smr, n, algo == "allconcur+")
-    gen = sim.workload
-    sim.start()
-    sim.run(until=lambda: all(c.acked >= rpc for c in gen.clients),
-            max_time=60.0)
-    assert all(c.acked >= rpc for c in gen.clients)
-    return rec
 
 
 # ------------------------------------------- exact event cross-validation
@@ -126,9 +39,9 @@ class TestEventExactness:
     @pytest.mark.parametrize("n", [8, 16])
     @pytest.mark.parametrize("algo", MODES)
     def test_open_loop_acks_bit_for_bit(self, algo, n):
-        entries, compl, subs, acks = _run_open_loop(algo, n)
-        e, c = _timelines(entries, compl, n)
-        by_server, s = _server_fifo(subs, acks, n)
+        entries, compl, subs, acks = run_open_loop(algo, n)
+        e, c = event_timelines(entries, compl, n)
+        by_server, s = server_fifo(subs, acks, n)
         res = client_latencies(e, c, s, mode=algo, batch_max=2)
         checked = 0
         for h in range(n):
@@ -144,10 +57,10 @@ class TestEventExactness:
         # burst arrivals far above per-round capacity: requests queue across
         # many rounds, partially-filled DUAL batches absorb later arrivals
         for algo in MODES:
-            entries, compl, subs, acks = _run_open_loop(
+            entries, compl, subs, acks = run_open_loop(
                 algo, 8, rpc=10, batch_max=2, rate=80000.0)
-            e, c = _timelines(entries, compl, 8)
-            by_server, s = _server_fifo(subs, acks, 8)
+            e, c = event_timelines(entries, compl, 8)
+            by_server, s = server_fifo(subs, acks, 8)
             res = client_latencies(e, c, s, mode=algo, batch_max=2)
             for h in range(8):
                 for j, u in enumerate(by_server[h]):
@@ -208,7 +121,8 @@ class TestPallasBitexact:
         from repro.kernels import segment_counts, segment_counts_reference
         rng = np.random.default_rng(0)
         for shape_s, shape_e in [((37,), (11,)), ((3, 200), (3, 130)),
-                                 ((2, 2, 50), (2, 2, 257))]:
+                                 ((2, 2, 50), (2, 2, 257)),
+                                 ((2, 3000), (2, 70))]:   # 3 client blocks
             s = rng.uniform(0, 1, shape_s)
             s.flat[::7] = np.inf                       # ragged padding
             s.flat[1] = 0.5                            # exact ties at an edge
@@ -220,6 +134,32 @@ class TestPallasBitexact:
             brute = (s[..., :, None] <= e[..., None, :]).sum(-2)
             assert (ref == brute).all()
             assert (ker == ref).all()
+
+    def test_order_key_counts_are_exact(self):
+        """The "vec" counts compare exact order words, not float values:
+        times one ulp apart, ties, +inf padding and -0.0 all count as the
+        float comparison does on the host."""
+        import jax.numpy as jnp
+
+        from repro.kernels import segment_counts_reference
+        from repro.kernels.compat import enable_x64
+        from repro.vecsim.clients import _ascending_counts, order_keys
+
+        rng = np.random.default_rng(3)
+        base = np.sort(rng.uniform(1e-4, 1e-2, (3, 400)), axis=1)
+        s = np.sort(np.concatenate([base, np.nextafter(base, 1.0),
+                                    np.full((3, 9), np.inf)], axis=1),
+                    axis=1)
+        s[0, 0] = -0.0
+        e = np.sort(np.concatenate([base[:, ::7], np.nextafter(
+            base[:, 3::11], 0.0), np.zeros((3, 1))], axis=1), axis=1)
+        with enable_x64():
+            got = np.asarray(_ascending_counts(
+                tuple(map(jnp.asarray, order_keys(s))),
+                tuple(map(jnp.asarray, order_keys(e)))))
+            ref = np.asarray(segment_counts_reference(s, e))
+        assert (got == ref).all()
+        assert (got == (s[:, :, None] <= e[:, None, :]).sum(1)).all()
 
     def test_segment_counts_under_vmap(self):
         import jax
@@ -361,7 +301,7 @@ class TestDeterminism:
         # path, and bit parity is only promised within compiled code)
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.kernels.compat import enable_x64
         a = arrival_times(9, 8, 4, rate=2000.0)
         with enable_x64():
             base = jax.random.PRNGKey(9)
@@ -419,3 +359,40 @@ class TestMonteCarloClients:
                                     mode="allconcur+", batch_max=16,
                                     engine="pallas")
         assert res == res_p
+
+    def test_mc_pool_equals_per_schedule_client_latencies(self):
+        """The pooled run is client_latencies per schedule (the spliced
+        timeline shared by every server, no extra ack lag), pooled: the
+        same served count and percentiles, exactly."""
+        mct = monte_carlo_times(120e-6, 180e-6, n=8, batch=16, mtbf=0.05,
+                                rounds=256, n_schedules=6, seed=5)
+        s = server_streams(arrival_times(2, 256, 2, rate=2 / 0.01), 8)
+        res = mc_client_latencies(mct.entry, mct.deliver, s,
+                                  mode="allconcur", batch_max=16)
+        lats = []
+        for e, d in zip(mct.entry, mct.deliver):
+            one = client_latencies(np.tile(e, (8, 1)), np.tile(d, (8, 1)), s,
+                                   mode="allconcur", batch_max=16, ack_lag=0)
+            lats.append(one.latency[one.valid])
+        pool = np.sort(np.concatenate(lats))
+        assert res["served"] == pool.size > 0
+        for p, v in res["percentiles"].items():
+            assert v == pool[min(int(p * pool.size), pool.size - 1)]
+
+    @pytest.mark.parametrize("size", [0, 1, 2, 17, 1000])
+    def test_pooled_percentiles_equal_sorted_nearest_rank(self, size):
+        """In-place partition picks the element a full sort would, with
+        ties and repeated ranks; NaN when nothing was served."""
+        from repro.vecsim.clients import _pooled_percentiles
+
+        rng = np.random.default_rng(size)
+        x = rng.exponential(1e-3, size)
+        x[::3] = np.round(x[::3], 5)                      # ties
+        ps = (0.0, 0.5, 0.5, 0.99, 0.999, 1.0)
+        got = _pooled_percentiles(x.copy(), ps)
+        srt = np.sort(x)
+        for p in ps:
+            if size == 0:
+                assert np.isnan(got[p])
+            else:
+                assert got[p] == srt[min(int(p * size), size - 1)]

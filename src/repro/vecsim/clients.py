@@ -74,6 +74,7 @@ import numpy as np
 from ..smr.percentiles import nearest_rank_index
 from ..smr.workload import ZipfianGenerator
 from .engine import RoundTimes, run_reliable, run_unreliable
+from .spans import span
 from .topology import reliable_tables, smr_message_bytes, unreliable_tables
 
 MODES = ("allconcur+", "allconcur", "allgather")
@@ -161,13 +162,14 @@ def server_streams(arrivals, n: int) -> np.ndarray:
     ``cid % n`` (the event harness's ``assign_round_robin``).  Returns
     ``[n, (num_clients // n) * q]`` submit times, ascending per server.
     """
-    arrivals = np.asarray(arrivals, dtype=np.float64)
-    c, q = arrivals.shape
-    if c % n:
-        raise ValueError(f"num_clients={c} must be a multiple of n={n}")
-    # cid = i * n + h  ->  [cps, n, q] -> per-server flat stream
-    s = arrivals.reshape(c // n, n, q).transpose(1, 0, 2).reshape(n, -1)
-    return np.sort(s, axis=1)
+    with span("server_streams"):
+        arrivals = np.asarray(arrivals, dtype=np.float64)
+        c, q = arrivals.shape
+        if c % n:
+            raise ValueError(f"num_clients={c} must be a multiple of n={n}")
+        # cid = i * n + h  ->  [cps, n, q] -> per-server flat stream
+        s = arrivals.reshape(c // n, n, q).transpose(1, 0, 2).reshape(n, -1)
+        return np.sort(s, axis=1)
 
 
 # --------------------------------------------------------------------------
@@ -280,9 +282,13 @@ def _assign_rounds(entry, s, *, delta: int, batch_max: int,
     """0-based abcast round of every request, ``[T, n, M]`` int32, for
     ``[T, 1 or n, K]`` round entries against ``[n, M]`` submits."""
     from ..kernels.compat import enable_x64
-    fn = _compiled_pipeline(delta, int(batch_max), engine)
-    with enable_x64():
-        return np.asarray(fn(entry, s, order_keys(entry), order_keys(s)))
+    with span("order_keys"):
+        e_keys, s_keys = order_keys(entry), order_keys(s)
+    with span("dispatch"):
+        fn = _compiled_pipeline(delta, int(batch_max), engine)
+        with enable_x64():
+            rounds = fn(entry, s, e_keys, s_keys)
+    return np.asarray(rounds)
 
 
 def _host_gather(a0, ack_times, s, lag: int):
@@ -301,9 +307,10 @@ def _pooled_percentiles(served: np.ndarray, ps) -> dict:
     partitioned in place); NaN when empty."""
     if not served.size:
         return {p: float("nan") for p in ps}
-    idx = [nearest_rank_index(served.size, float(p)) for p in ps]
-    served.partition(sorted(set(idx)))
-    return {p: float(served[i]) for p, i in zip(ps, idx)}
+    with span("percentiles"):
+        idx = [nearest_rank_index(served.size, float(p)) for p in ps]
+        served.partition(sorted(set(idx)))
+        return {p: float(served[i]) for p, i in zip(ps, idx)}
 
 
 @dataclass(frozen=True)
@@ -341,9 +348,10 @@ def client_latencies(entry, ack_times, submits, *, mode: str,
     s = np.asarray(submits, np.float64)
     a0 = _assign_rounds(entry[None], s, delta=_delta(mode),
                         batch_max=batch_max, engine=engine)[0]
-    ack, lat, valid = _host_gather(a0, np.asarray(ack_times, np.float64), s,
-                                   lag)
-    served = lat[valid]
+    with span("gather"):
+        ack, lat, valid = _host_gather(a0, np.asarray(ack_times, np.float64),
+                                       s, lag)
+        served = lat[valid]
     return ClientLatencies(round_idx=a0, ack=ack, latency=lat, valid=valid,
                            percentiles=_pooled_percentiles(served, ps),
                            served=int(served.size))
@@ -364,11 +372,12 @@ def mc_client_latencies(mc_entry, mc_deliver, submits, *, mode: str,
     s = np.asarray(submits, np.float64)
     a0 = _assign_rounds(entry[:, None, :], s, delta=_delta(mode),
                         batch_max=batch_max, engine=engine)   # [S, n, M]
-    _ack, lat, valid = _host_gather(
-        a0, np.asarray(mc_deliver, np.float64)[:, None, :], s, 0)
-    del a0, _ack
-    served = lat[valid]
-    del lat, valid
+    with span("gather"):
+        _ack, lat, valid = _host_gather(
+            a0, np.asarray(mc_deliver, np.float64)[:, None, :], s, 0)
+        del a0, _ack
+        served = lat[valid]
+        del lat, valid
     return {"percentiles": _pooled_percentiles(served, ps),
             "served": int(served.size), "schedules": int(entry.shape[0])}
 

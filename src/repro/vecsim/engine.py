@@ -47,6 +47,8 @@ from typing import Tuple
 
 import numpy as np
 
+from .spans import span
+
 BIG = 1e12          # "not yet known" sentinel (finite: avoids inf-inf NaNs)
 ENGINES = ("vec", "pallas")   # jnp gather relaxation | Pallas tropical kernel
 _EPS = 1e-9         # fixpoint convergence tolerance (seconds): one ns is 4+
@@ -209,19 +211,21 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
     if not max_iters:
         max_iters = 2 * int(np.ceil(np.log2(max(n, 2)))) + 8
 
-    fn = _compiled_unreliable(n, K, max_iters, engine)
     def flat(a):
         return np.asarray(a, np.float64).reshape(
             (-1,) + a.shape[len(batch_shape):])
 
-    with enable_x64():
+    with span("dispatch"), enable_x64():
+        fn = _compiled_unreliable(n, K, max_iters, engine)
         C, tstart, iters = fn(
             parent.reshape((-1, n, n)).astype(np.int32),
             flat(np.asarray(send_off)), flat(np.asarray(occ)),
             flat(np.asarray(prop)))
     C = np.asarray(C).reshape(batch_shape + (K, n))
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
-    return RoundTimes(completion=C, start=tstart, iterations=int(np.max(iters)))
+    with span("sync"):
+        iterations = int(np.max(iters))
+    return RoundTimes(completion=C, start=tstart, iterations=iterations)
 
 
 @functools.lru_cache(maxsize=64)
@@ -367,31 +371,37 @@ def run_reliable(adj, edge_off, occ, prop, *, rounds: int,
                              flat(np.asarray(prop)))
 
     # pad predecessor lists to the max in-degree across the batch
-    dmax = int(adj_f.sum(axis=1).max())
-    pred = np.zeros((B, n, dmax), dtype=np.int32)
-    pred_cost = np.full((B, n, dmax), BIG, dtype=np.float64)
-    pred_mask = np.zeros((B, n, dmax), dtype=bool)
-    for b in range(B):
-        for v in range(n):
-            us = np.flatnonzero(adj_f[b, :, v])
-            pred[b, v, :len(us)] = us
-            pred_cost[b, v, :len(us)] = eoff_f[b, us, v] + prop_f[b, us, v]
-            pred_mask[b, v, :len(us)] = True
+    with span("tables"):
+        dmax = int(adj_f.sum(axis=1).max())
+        pred = np.zeros((B, n, dmax), dtype=np.int32)
+        pred_cost = np.full((B, n, dmax), BIG, dtype=np.float64)
+        pred_mask = np.zeros((B, n, dmax), dtype=bool)
+        for b in range(B):
+            for v in range(n):
+                us = np.flatnonzero(adj_f[b, :, v])
+                pred[b, v, :len(us)] = us
+                pred_cost[b, v, :len(us)] = (eoff_f[b, us, v]
+                                             + prop_f[b, us, v])
+                pred_mask[b, v, :len(us)] = True
 
-    fn = _compiled_reliable(n, K, dmax, max_iters, True, engine)
-    with enable_x64():
+    with span("dispatch"), enable_x64():
+        fn = _compiled_reliable(n, K, dmax, max_iters, True, engine)
         C, tstart, iters, resid = fn(pred, pred_cost, pred_mask, occ_f)
     C, resid = np.asarray(C), np.asarray(resid)
     # insurance: the warm-started solve must agree with the trustworthy cold
     # prefix and be fully resolved; otherwise redo the whole batch cold
     if (resid > 1e-9).any() or not np.isfinite(C).all() or (C > BIG / 2).any():
-        fn = _compiled_reliable(n, K, dmax, 8 * max_iters, False, engine)
-        with enable_x64():
-            C, tstart, iters, _ = fn(pred, pred_cost, pred_mask, occ_f)
-        C = np.asarray(C)
+        with span("resolve"):
+            with span("dispatch"), enable_x64():
+                fn = _compiled_reliable(n, K, dmax, 8 * max_iters, False,
+                                        engine)
+                C, tstart, iters, _ = fn(pred, pred_cost, pred_mask, occ_f)
+            C = np.asarray(C)
     C = C.reshape(batch_shape + (K, n))
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
-    return RoundTimes(completion=C, start=tstart, iterations=int(np.max(iters)))
+    with span("sync"):
+        iterations = int(np.max(iters))
+    return RoundTimes(completion=C, start=tstart, iterations=iterations)
 
 
 @functools.lru_cache(maxsize=64)

@@ -35,6 +35,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .spans import span
+
 BIG = 1e12
 
 
@@ -163,7 +165,8 @@ def _mc_run(du: float, dr: float, *, n: int, batch: int, mtbf: float,
     eon_idx = rounds + 1 if eon_round is None else int(eon_round)
     n_post = n if n2 is None else int(n2)
 
-    with enable_x64():
+    # the crash draws, the program (built anew on every call) and its launch
+    with span("dispatch"), enable_x64():
         key = jax.random.PRNGKey(seed)
         gaps = jax.random.exponential(key, (n_schedules, max_failures),
                                       dtype=jnp.float64) * mtbf

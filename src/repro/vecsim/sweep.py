@@ -16,7 +16,6 @@ Example::
 from __future__ import annotations
 
 import itertools
-import time
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -25,6 +24,7 @@ import numpy as np
 from ..core.digraph import resilience_degree
 from . import engine as _engine
 from . import topology
+from .spans import span
 
 UNRELIABLE_MODES = ("allconcur+", "allgather")
 
@@ -56,7 +56,6 @@ class SweepResult:
     throughput: np.ndarray        # [C] txn / s / server
     round_period: np.ndarray      # [C] seconds, steady-state round length
     completion: List[np.ndarray]  # per config: [rounds, n] completion times
-    wall_seconds: float = 0.0
 
     def table(self) -> List[Dict]:
         rows = []
@@ -106,7 +105,6 @@ def sweep(configs: Sequence[SweepConfig], *,
     ``engine="pallas"`` runs the inner relaxation on the tropical min-plus
     Pallas kernel (bit-for-bit equal to the default jnp path)."""
     all_configs = list(configs)
-    t0 = time.time()
 
     # deterministic dedup: unique points computed, duplicates share results
     uniq: Dict[Tuple, int] = {}
@@ -133,26 +131,20 @@ def sweep(configs: Sequence[SweepConfig], *,
         key, idxs = item
         kind, n = key[0], key[1]
         rounds = key[-1]
-        if kind == "unreliable":
-            tabs = [topology.unreliable_tables(
-                n, network=configs[i].network, batch=configs[i].batch,
-                mode=configs[i].algo) for i in idxs]
-            rt = _engine.run_unreliable(
-                np.stack([t.parent for t in tabs]),
-                np.stack([t.send_off for t in tabs]),
-                np.stack([t.occ for t in tabs]),
-                np.stack([t.prop for t in tabs]), rounds=rounds,
-                engine=engine)
-        else:
-            tabs2 = [topology.reliable_tables(
-                n, d=configs[i].resolved_d(), network=configs[i].network,
-                batch=configs[i].batch) for i in idxs]
-            rt = _engine.run_reliable(
-                np.stack([t.adj for t in tabs2]),
-                np.stack([t.edge_off for t in tabs2]),
-                np.stack([t.occ for t in tabs2]),
-                np.stack([t.prop for t in tabs2]), rounds=rounds,
-                engine=engine)
+        with span("tables"):
+            if kind == "unreliable":
+                tabs = [topology.unreliable_tables(
+                    n, network=configs[i].network, batch=configs[i].batch,
+                    mode=configs[i].algo) for i in idxs]
+                run, fields = _engine.run_unreliable, ("parent", "send_off")
+            else:
+                tabs = [topology.reliable_tables(
+                    n, d=configs[i].resolved_d(), network=configs[i].network,
+                    batch=configs[i].batch) for i in idxs]
+                run, fields = _engine.run_reliable, ("adj", "edge_off")
+            arrays = [np.stack([getattr(t, f) for t in tabs])
+                      for f in fields + ("occ", "prop")]
+        rt = run(*arrays, rounds=rounds, engine=engine)
         for j, i in enumerate(idxs):
             one = _engine.RoundTimes(completion=rt.completion[j],
                                     start=rt.start[j],
@@ -177,5 +169,4 @@ def sweep(configs: Sequence[SweepConfig], *,
     alias_a = np.asarray(alias, dtype=np.intp)
     return SweepResult(configs=all_configs, median_latency=med[alias_a],
                        throughput=thr[alias_a], round_period=period[alias_a],
-                       completion=[completion[a] for a in alias],
-                       wall_seconds=time.time() - t0)
+                       completion=[completion[a] for a in alias])

@@ -42,6 +42,7 @@ Semantics replicated from ``repro.sim.runner`` / ``repro.core.server``:
 from __future__ import annotations
 
 import functools
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -65,10 +66,14 @@ def _jax():
 @dataclass(frozen=True)
 class RoundTimes:
     """Per-config round trajectory: ``completion[k, v]`` is the time server v
-    completes round k+1 (k = 0..K-1); ``start[k, v]`` is its entry time."""
+    completes round k+1 (k = 0..K-1); ``start[k, v]`` is its entry time.
+    ``relaxed`` counts the rounds relaxed on the device (K unless a G_U
+    timeline was extended in closed form; 0 where the producer does not
+    say)."""
     completion: np.ndarray   # [..., K, n]
     start: np.ndarray        # [..., K, n]
     iterations: int
+    relaxed: int = 0
 
 
 def _nic_scan(jnp, keys, occ, tx0):
@@ -144,6 +149,31 @@ def _nic_scan_tpu(jnp, keys, occ, tx0):
 # whole trajectory is a lax.scan over rounds carrying (round entry times,
 # NIC free times), with a small per-round fixpoint inside (~tree depth
 # iterations over [n, n] arrays instead of a joint K-round relaxation).
+#
+# Settle and extend.  The round map takes the carry through max, min and
+# plus only, so it commutes with a common shift of every time: once the
+# carry of round k+1 is the carry of round k shifted by one period
+# lambda, every later round is the same round shifted again.  With one
+# message size a failure-free run settles into such a period within a few
+# dozen rounds, so a long horizon (K > SETTLE_PREFIX = P) relaxes only
+# its first P rounds on the device, returning the whole carry per round.
+# The host then checks, in IEEE float64, that over the last SETTLE_WINDOW
+# rounds of the prefix every step of the carry (entry time and NIC free
+# time, every server) equals one common period within SETTLE_TOL * lambda,
+# and builds the tail in closed form as the plain reference does:
+# E[k] = E[P-1] + (k-P+1) * lambda_v per server, C[k] = E[k+1].  A drift
+# below SETTLE_TOL * lambda per round moves round k by at most
+# k * SETTLE_TOL * lambda, a relative error of SETTLE_TOL.  A prefix that
+# fails the check (a longer transient, a period spanning several rounds)
+# falls back to the full K-round scan, whose result is then exactly what
+# it was without this path.  K <= P runs the full scan unchanged.
+
+SETTLE_PREFIX = 256     # P: rounds relaxed on the device before the check
+SETTLE_WINDOW = 128     # trailing rounds of the prefix the check covers
+SETTLE_TOL = 2e-12      # relative step tolerance of the check: the TPU's
+                        # emulated float64 steps by up to 1.5e-12 of the
+                        # period there (CPU: 3e-14)
+
 
 def _unreliable_round(jax, jnp, tstart, tx0, parent, send_off, occ, prop,
                       prop_from_parent, max_iters, relax_cost=None):
@@ -199,7 +229,15 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
     """Relax K failure-free G_U rounds.  Batched: all array arguments may
     carry leading batch dimensions (vmapped out here).  ``engine="pallas"``
     lowers the relaxation onto the tropical min-plus kernel (bit-for-bit
-    equal to the default jnp path; interpret-mode off-TPU)."""
+    equal to the default jnp path; interpret-mode off-TPU).
+
+    K <= SETTLE_PREFIX relaxes all K rounds in one scan.  A longer horizon
+    relaxes the first SETTLE_PREFIX rounds, checks that the whole carry
+    (entry and NIC free times of every server) steps by one common period
+    within SETTLE_TOL over the prefix's last SETTLE_WINDOW rounds, and
+    extends it in closed form (relative error at most SETTLE_TOL at any
+    round); if any batch member fails the check, the full K-round scan
+    runs instead (see the G_U comment block above)."""
     jax, jnp = _jax()
     from ..kernels.compat import enable_x64
     if engine not in ENGINES:
@@ -215,22 +253,65 @@ def run_unreliable(parent, send_off, occ, prop, *, rounds: int,
         return np.asarray(a, np.float64).reshape(
             (-1,) + a.shape[len(batch_shape):])
 
-    with span("dispatch"), enable_x64():
-        fn = _compiled_unreliable(n, K, max_iters, engine)
-        C, tstart, iters = fn(
-            parent.reshape((-1, n, n)).astype(np.int32),
+    args = (parent.reshape((-1, n, n)).astype(np.int32),
             flat(np.asarray(send_off)), flat(np.asarray(occ)),
             flat(np.asarray(prop)))
+    C = None
+    if K > SETTLE_PREFIX:
+        P = SETTLE_PREFIX
+        with span("dispatch"), enable_x64():
+            fn = _compiled_unreliable(n, P, max_iters, engine,
+                                      free_times=True)
+            C_p, ts_p, free_p, iters = fn(*args)
+        C_p, ts_p = np.asarray(C_p), np.asarray(ts_p)
+        period = _settled_period(ts_p, C_p, np.asarray(free_p))
+        if period is not None:
+            with span("extend"):
+                C, tstart = _extend(ts_p, C_p, period, K)
+            relaxed = P
+    if C is None:                   # short horizon, or the prefix unsettled
+        with (span("rescan") if K > SETTLE_PREFIX else nullcontext(),
+              span("dispatch"), enable_x64()):
+            fn = _compiled_unreliable(n, K, max_iters, engine)
+            C, tstart, iters = fn(*args)
+        relaxed = K
     C = np.asarray(C).reshape(batch_shape + (K, n))
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
     with span("sync"):
         iterations = int(np.max(iters))
-    return RoundTimes(completion=C, start=tstart, iterations=iterations)
+    return RoundTimes(completion=C, start=tstart, iterations=iterations,
+                      relaxed=relaxed)
+
+
+def _settled_period(ts, C, free_end):
+    """Per-server period ``[B, n]`` of a relaxed prefix ``[B, P, n]``, or
+    None unless every carry step over the last SETTLE_WINDOW rounds equals
+    one common period within SETTLE_TOL (IEEE float64 on the host)."""
+    W = SETTLE_WINDOW
+    entry = np.concatenate([ts, C[:, -1:]], axis=1)   # carry: C[k] = E[k+1]
+    steps = np.concatenate([np.diff(entry[:, -W - 1:], axis=1),
+                            np.diff(free_end[:, -W - 1:], axis=1)], axis=2)
+    lam = (entry[:, -1] - entry[:, -W - 1]).mean(axis=1) / W    # common [B]
+    drift = np.abs(steps - lam[:, None, None]) / lam[:, None, None]
+    if not (np.all(lam > 0) and np.all(drift <= SETTLE_TOL)):
+        return None
+    return (ts[:, -1] - ts[:, -W - 1]) / W
+
+
+def _extend(ts, C, period, K):
+    """Completion and entry ``[B, K, n]``: the prefix, then its last entry
+    plus whole periods (``E[k] = E[P-1] + (k-P+1) * period``,
+    ``C[k] = E[k+1]``)."""
+    P = ts.shape[1]
+    k = np.arange(1, K - P + 2, dtype=np.float64)[None, :, None]
+    tail = ts[:, -1:] + k * period[:, None, :]
+    return (np.concatenate([C, tail[:, 1:]], axis=1),
+            np.concatenate([ts, tail[:, :-1]], axis=1))
 
 
 @functools.lru_cache(maxsize=64)
 def _compiled_unreliable(n: int, K: int, max_iters: int,
-                         engine: str = "vec"):
+                         engine: str = "vec", free_times: bool = False):
     jax, jnp = _jax()
     from ..kernels.compat import enable_x64
 
@@ -254,15 +335,18 @@ def _compiled_unreliable(n: int, K: int, max_iters: int,
                 C, free_end, it = _unreliable_round(
                     jax, jnp, tstart, tx0, parent, send_off, occ, prop,
                     prop_from_parent, max_iters, relax_cost)
-                return (C, free_end), (tstart, C, it)
+                out = (tstart, C, it) + ((free_end,) if free_times else ())
+                return (C, free_end), out
 
             init = (jnp.zeros(n, jnp.float64), jnp.zeros(n, jnp.float64))
-            _carry, (ts, C, its) = jax.lax.scan(round_step, init, None,
-                                                length=K)
-            return C, ts, jnp.max(its)
+            _carry, (ts, C, its, *free) = jax.lax.scan(round_step, init, None,
+                                                       length=K)
+            return (C, ts, *free, jnp.max(its))
 
-        # the program's name is what compile logs and profiles report
-        single.__name__ = f"vecsim_unreliable_n{n}_k{K}_{engine}"
+        # the program's name is what compile logs and profiles report; the
+        # prefix of a settle-and-extend run also returns the NIC free times
+        single.__name__ = (f"vecsim_unreliable_n{n}_k{K}_"
+                           f"{'prefix_' if free_times else ''}{engine}")
         return jax.jit(jax.vmap(single))
 
 
@@ -401,7 +485,8 @@ def run_reliable(adj, edge_off, occ, prop, *, rounds: int,
     tstart = np.asarray(tstart).reshape(batch_shape + (K, n))
     with span("sync"):
         iterations = int(np.max(iters))
-    return RoundTimes(completion=C, start=tstart, iterations=iterations)
+    return RoundTimes(completion=C, start=tstart, iterations=iterations,
+                      relaxed=K)
 
 
 @functools.lru_cache(maxsize=64)
